@@ -40,6 +40,7 @@ from repro.data.synthetic import (DEFAULT_PREDICATES, make_camera_stream,  # noq
 from repro.engine import (PredicateClause, QuerySpec,  # noqa: E402
                           plan_query)
 from repro.engine.ingest import indexed_execute  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -166,4 +167,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -18,6 +18,7 @@ import numpy as np  # noqa: E402
 from repro.configs.registry import smoke_config  # noqa: E402
 from repro.core.lm_cascade import (LMLevel, calibrate, expected_cost,  # noqa: E402
                                    lm_predicate_score, run_lm_cascade)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models.factory import build_model  # noqa: E402
 from repro.train.optimizer import adamw  # noqa: E402
 
@@ -89,4 +90,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -45,6 +45,7 @@ from repro.data.synthetic import (DEFAULT_PREDICATES, make_corpus,  # noqa: E402
 from repro.engine import (And, Join, Not, Or, Pred, QuerySpec,  # noqa: E402
                           ScanEngine, execute_join, execute_tree,
                           naive_join_pairs, naive_tree_rows, plan_query)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -150,4 +151,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

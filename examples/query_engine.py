@@ -55,6 +55,7 @@ from repro.data.synthetic import (DEFAULT_PREDICATES, make_corpus,  # noqa: E402
                                   make_multi_corpus, three_way_split)
 from repro.engine import (PredicateClause, QuerySpec,  # noqa: E402
                           naive_scan, plan_query)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 
 EXPLAIN_HELP = """\
@@ -207,4 +208,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -27,6 +27,7 @@ from repro.core.selector import pareto_set, select  # noqa: E402
 from repro.core.transforms import representation_space  # noqa: E402
 from repro.data.synthetic import (DEFAULT_PREDICATES, make_corpus,  # noqa: E402
                                   three_way_split)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -103,4 +104,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -32,6 +32,7 @@ from repro.core.pipeline import build_cascade_service, train_cnn  # noqa: E402
 from repro.core.transforms import Representation, apply_transform  # noqa: E402
 from repro.data.synthetic import DEFAULT_PREDICATES, make_corpus  # noqa: E402
 from repro.engine.scan import CompiledCascade  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models.cnn import cnn_predict_proba  # noqa: E402
 from repro.serve.batcher import Request  # noqa: E402
 
@@ -183,4 +184,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

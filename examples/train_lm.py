@@ -12,9 +12,11 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.train import main  # noqa: E402
 
 if __name__ == "__main__":
     if "--steps" not in " ".join(sys.argv):
         sys.argv += ["--steps", "200"]
+    use_compile_cache()
     main()

@@ -232,7 +232,9 @@ def make_fused_ingest(model_fns: Sequence[Callable], thresholds,
     pyramid materialization on the unfused path (the scan engine injects
     its module-global so tests can count calls); default is
     core.transforms.materialize_pyramid. ``use_kernel=None`` resolves to
-    True on TPU when ``stage0`` carries real CNN params. ``int8`` swaps
+    the compiled kernel on TPU when ``stage0`` carries real CNN params
+    the kernel can hold in VMEM (image_transform.stage0_fits: every
+    reduced-grid model; a 224 px trusted CNN runs unfused). ``int8`` swaps
     stage-0's weights for the int8-quantized copy (dequantize-at-use;
     requires ``stage0.qparams``). ``emit_scores=True`` additionally
     returns the raw level-0 probability scores (B,) as a third output —
@@ -243,59 +245,58 @@ def make_fused_ingest(model_fns: Sequence[Callable], thresholds,
     scores for confident stage-0 decisions and candidate ranking."""
     out_res = [int(r) for r in out_res]
     need = sorted({r.resolution for r in reps} | set(out_res))
-    if use_kernel is None:
-        use_kernel = (stage0 is not None
-                      and jax.default_backend() == "tpu")
     if use_kernel and stage0 is None:
         raise ValueError("use_kernel requires stage0 params")
     if int8 and (stage0 is None or stage0.qparams is None):
         raise ValueError("int8 requires stage0.qparams")
     mat = materialize if materialize is not None else materialize_pyramid
+    on_tpu = jax.default_backend() == "tpu"
 
     model_fns = list(model_fns)
-    if int8 and not use_kernel:
+    unfused_fns = list(model_fns)
+    if int8:
         # unfused int8: dequantize once at build, identical arithmetic
         # to the kernel's dequantize-at-use epilogue
         from repro.models.cnn import cnn_predict_proba, dequantize_cnn
-        model_fns[0] = partial(cnn_predict_proba,
-                               dequantize_cnn(stage0.qparams))
+        unfused_fns[0] = partial(cnn_predict_proba,
+                                 dequantize_cnn(stage0.qparams))
 
-    if use_kernel:
-        from repro.kernels.image_transform import fused_pyramid_stage0
-        qp = stage0.qparams if int8 else None
+    def kernel_for(base: int) -> bool:
+        if use_kernel is not None:
+            return bool(use_kernel)
+        if stage0 is None or not on_tpu:
+            return False
+        from repro.kernels.image_transform import stage0_fits
+        return stage0_fits(stage0, base, [r for r in need if r != base],
+                           int8)
 
-        def run(imgs):
-            base = imgs.shape[1]
+    def run(imgs):
+        base = imgs.shape[1]
+        small = [r for r in need if r != base]
+        if kernel_for(base):
+            from repro.kernels.image_transform import fused_pyramid_stage0
             levels, s0 = fused_pyramid_stage0(
-                imgs, [r for r in need if r != base],
-                stage0.params, stage0.rep, qparams=qp)
+                imgs, small, stage0.params, stage0.rep,
+                qparams=stage0.qparams if int8 else None)
             pyr = {base: imgs, **levels}
-            labels, _ = run_cascade_on_pyramid(
-                pyr, model_fns, thresholds, reps, capacities,
-                level0_scores=s0)
-            emitted = {r: pyr[r] for r in out_res}
-            if emit_scores:
-                return labels, emitted, s0
-            return labels, emitted
-    else:
-        def run(imgs):
-            base = imgs.shape[1]
-            pyr = dict(mat(imgs, [r for r in need if r != base]))
+            fns = model_fns
+        else:
+            pyr = dict(mat(imgs, small))
             pyr.setdefault(base, imgs)
+            fns = unfused_fns
             s0 = None
             if emit_scores:
                 # score level 0 explicitly (same input derivation as
                 # run_cascade_on_pyramid's get_input) and feed it back
                 # as level0_scores — the composition is the identical
                 # jnp program, so labels stay bit-exact
-                s0 = model_fns[0](color_transform(
-                    pyr[reps[0].resolution], reps[0].color))
-            labels, _ = run_cascade_on_pyramid(
-                pyr, model_fns, thresholds, reps, capacities,
-                level0_scores=s0)
-            emitted = {r: pyr[r] for r in out_res}
-            if emit_scores:
-                return labels, emitted, s0
-            return labels, emitted
+                s0 = fns[0](color_transform(pyr[reps[0].resolution],
+                                            reps[0].color))
+        labels, _ = run_cascade_on_pyramid(pyr, fns, thresholds, reps,
+                                           capacities, level0_scores=s0)
+        emitted = {r: pyr[r] for r in out_res}
+        if emit_scores:
+            return labels, emitted, s0
+        return labels, emitted
 
     return jax.jit(run) if jit else run

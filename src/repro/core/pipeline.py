@@ -26,6 +26,12 @@ from repro.core.transforms import (Representation, apply_transform,
 from repro.models.cnn import bce_loss, cnn_predict_proba, init_cnn
 from repro.train.optimizer import adamw
 
+# Scoring and profiling run every bank model through this one jitted
+# predictor at one batch width, so each model shape compiles once: on
+# TPU a Precision.HIGHEST conv program takes seconds to compile.
+_predict = jax.jit(cnn_predict_proba)
+SCORE_BATCH = 32
+
 
 @dataclass
 class ModelEntry:
@@ -61,12 +67,20 @@ class ModelBank:
         power every downstream cascade simulation. All representations
         the bank needs are materialized in ONE progressive pyramid pass
         (core/transforms.materialize_representations) instead of each
-        model re-transforming from the raw base images."""
+        model re-transforming from the raw base images; models score
+        ``SCORE_BATCH`` rows per call (the last call zero-padded)."""
         rep_cache = materialize_representations(
             jnp.asarray(raw_images), [e.rep for e in self.entries])
-        return np.stack([
-            np.asarray(cnn_predict_proba(e.params, rep_cache[e.rep]))
-            for e in self.entries])
+        n = len(raw_images)
+        rows = []
+        for e in self.entries:
+            x = np.asarray(rep_cache[e.rep])
+            x = np.concatenate([x, np.zeros((-n % SCORE_BATCH,)
+                                            + x.shape[1:], x.dtype)])
+            rows.append(np.concatenate([
+                np.asarray(_predict(e.params, x[i:i + SCORE_BATCH]))
+                for i in range(0, len(x), SCORE_BATCH)])[:n])
+        return np.stack(rows)
 
 
 # ------------------------------------------------------------- training ----
@@ -131,19 +145,19 @@ def train_model_grid(train_x, train_y, archs: Sequence[TahomaCNNConfig],
 
 
 # -------------------------------------------------------------- profiling --
-def profile_infer_costs(bank: ModelBank, sample_raw, *, batch: int = 32,
+def profile_infer_costs(bank: ModelBank, sample_raw, *,
+                        batch: int = SCORE_BATCH,
                         repeats: int = 3) -> dict[str, float]:
     """Measured seconds/image of pure inference (the cost profiler of
     Fig. 2, run in the current deployment)."""
     out = {}
     for e in bank.entries:
         x = apply_transform(jnp.asarray(sample_raw[:batch]), e.rep)
-        fn = jax.jit(lambda p, xx: cnn_predict_proba(p, xx))
-        fn(e.params, x).block_until_ready()
+        _predict(e.params, x).block_until_ready()
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()
-            fn(e.params, x).block_until_ready()
+            _predict(e.params, x).block_until_ready()
             best = min(best, time.perf_counter() - t0)
         out[e.name] = best / batch
     return out

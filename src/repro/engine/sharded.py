@@ -11,8 +11,8 @@ materialization. Two execution backends:
   index-slab per shard into a leading device axis and issues ONE
   ``jax.pmap`` dispatch over the shard devices
   (`launch/mesh.shard_devices`). Shard images are committed to their
-  devices once per scan (``jax.device_put_sharded``); each superstep
-  gathers device-locally, materializes the pyramid shard-locally, and
+  devices once per scan (one ``jax.device_put`` onto a 1-D shard
+  mesh); each superstep gathers device-locally, materializes the pyramid shard-locally, and
   ships back only labels plus the small non-base levels — the base
   level is regathered on-device at flush time, so per-superstep host
   traffic is index slabs and labels, not image-sized tensors. On a
@@ -105,6 +105,9 @@ class ShardedScanStats:
     n_devices: int = 1
     supersteps: int = 0                # lockstep group dispatches issued
     shards: list = field(default_factory=list)   # ScanStats per shard
+    # lockstep backend: shard index -> the device its staged image
+    # block was committed to (empty on the eager and serial backends)
+    staged_devices: dict = field(default_factory=dict)
 
     @property
     def rows_scanned(self) -> int:
@@ -452,8 +455,11 @@ class ShardedScanEngine:
         if not self.jit:
             return block
         import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
         devs = list(dict.fromkeys(self.devices))[:width]
-        return jax.device_put_sharded(list(block), devs)
+        mesh = Mesh(np.array(devs), ("shard",))
+        return jax.device_put(block,
+                              NamedSharding(mesh, PartitionSpec("shard")))
 
     def _lockstep(self, cascades, plan: ShardPlan, stores, stats,
                   monitor=None):
@@ -526,6 +532,10 @@ class ShardedScanEngine:
             return accepted
 
         block = self._stage_blocks(lanes, width, base_hw)
+        for piece in getattr(block, "addressable_shards", ()):
+            j = piece.index[0].start or 0
+            if j < len(group):
+                stats.staged_devices[group[j]] = piece.device
         # worklists[s][j]: (ids, pos, rows) segments awaiting evaluation
         # at stage s; pos indexes the lane's staged image block so the
         # base level is regathered device-side instead of host-carried

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -61,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool | None = None):
     """q (B,H,S,D); k/v (B,H,T,D) (kv heads already repeated).
     Returns (B,H,S,D)."""
     b, h, s, d = q.shape
@@ -89,6 +91,6 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)

@@ -1,10 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends the kernels execute in interpret mode (the kernel body
-runs in Python on CPU — correctness-exact, used by tests and this
-container); on TPU they compile to Mosaic. ``backend='ref'`` forces the
-pure-jnp oracle (the dry-run path, so XLA cost analysis sees the FLOPs —
-DESIGN.md §3).
+On a TPU backend the kernels compile to Mosaic; elsewhere they execute in
+interpret mode (the kernel body runs as plain JAX ops — what the CPU
+tests check against the oracles). The transform kernels take images as
+(H, W*3) row slabs, several per grid step (kernels/image_transform.py);
+tests/test_tpu_compile.py compiles them for a v5e chip.
+``backend='ref'`` forces the pure-jnp oracle (the dry-run path, so XLA
+cost analysis sees the FLOPs — DESIGN.md §3).
 """
 from __future__ import annotations
 

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                 l: int):
@@ -61,7 +63,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 
 
 def ssd_scan(x, dt, a, bmat, cmat, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """x (B,S,H,P); dt (B,S,H) >=0; a (H,) <0; b/c (B,S,N) shared across
     heads (n_groups=1). Returns y (B,S,H,P) float32 (pre-gating)."""
     b, s, h, p = x.shape
@@ -91,6 +93,6 @@ def ssd_scan(x, dt, a, bmat, cmat, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, 1, l, p), lambda g, c: (g, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, nc, l, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xs, dts, a_s, bs, cs)
     return y.reshape(b, h, s, p).transpose(0, 2, 1, 3)

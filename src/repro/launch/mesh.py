@@ -1,49 +1,31 @@
 """Production meshes. A FUNCTION (not a module-level constant) so importing
 this module never touches jax device state.
 
-``jax.sharding.AxisType`` (and ``make_mesh(axis_types=...)``) only exist on
-newer JAX releases; this module degrades gracefully to plain meshes on the
-installed version (every axis defaults to Auto semantics there anyway).
+Every mesh here has Auto axes (the compiler propagates shardings): the
+sharding policy and step builders are written for that, and
+``jax.make_mesh`` alone would make the axes Explicit.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """{'axis_types': (Auto,)*n} when the installed JAX supports it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    try:
-        params = inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):  # pragma: no cover - builtins
-        return {}
-    if "axis_types" not in params:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across JAX versions (with Auto axis types when the
-    installed version distinguishes them)."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+def _auto(axes) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(model_axis: int = 1, data_axis: int | None = None):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     data_axis = data_axis or (n // model_axis)
-    return make_mesh_compat((data_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((data_axis, model_axis), ("data", "model"),
+                         axis_types=_auto("dm"))
 
 
 # --------------------------------------------------- scan-shard placement --

@@ -6,8 +6,15 @@ the input representation space F (resolution x color) is applied by
 core/transforms.py BEFORE the model sees the image — jointly they form the
 paper's model design space A x F (§IV Def. 5/6).
 
-CNNs run in float32 (they are trained on CPU in this container; on TPU the
-convs lower to im2col + the MXU matmul kernel — kernels/matmul.py).
+CNNs run in float32. Inference (``cnn_forward``'s default) runs every
+conv and dense product at ``Precision.HIGHEST``: on TPU, XLA's default
+f32 precision is a single bf16 pass, which would put these scores ~1e-3
+away from the fused pyramid+stage-0 kernel (kernels/image_transform.py,
+whose dots run at the same HIGHEST precision) and flip labels near a
+threshold. Training (``bce_loss``) runs at the backend's default
+precision: the TPU compiler does not finish a HIGHEST conv gradient in
+reasonable time, and trained weights need no bit-level agreement with
+anything. On CPU the flag changes nothing.
 """
 from __future__ import annotations
 
@@ -15,6 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import TahomaCNNConfig
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def init_cnn(key, cfg: TahomaCNNConfig):
@@ -45,18 +54,21 @@ def _maxpool2(x):
                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
 
 
-def cnn_forward(params, images):
-    """images (B, H, W, C) float32 in [0,1] -> pre-sigmoid logits (B,)."""
+def cnn_forward(params, images, precision=HIGHEST):
+    """images (B, H, W, C) float32 in [0,1] -> pre-sigmoid logits (B,).
+    ``precision=None`` is the backend's default (training)."""
     h = images
     for layer in params["conv"]:
         h = jax.lax.conv_general_dilated(
             h, layer["w"], window_strides=(1, 1), padding="SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
         h = jax.nn.relu(h + layer["b"])
         h = _maxpool2(h)
     h = h.reshape(h.shape[0], -1)
-    h = jax.nn.relu(h @ params["dense_w"] + params["dense_b"])
-    return (h @ params["out_w"] + params["out_b"])[:, 0]
+    h = jax.nn.relu(jnp.dot(h, params["dense_w"], precision=precision)
+                    + params["dense_b"])
+    return (jnp.dot(h, params["out_w"], precision=precision)
+            + params["out_b"])[:, 0]
 
 
 def cnn_predict_proba(params, images):
@@ -121,8 +133,9 @@ def dequantize_cnn(qparams):
 
 
 def bce_loss(params, images, labels):
-    """Numerically-stable binary cross-entropy (labels in {0,1})."""
-    logits = cnn_forward(params, images)
+    """Numerically-stable binary cross-entropy (labels in {0,1}), at the
+    backend's default matmul precision."""
+    logits = cnn_forward(params, images, precision=None)
     z = jnp.maximum(logits, 0.0)
     loss = z - logits * labels + jnp.log1p(jnp.exp(-jnp.abs(logits)))
     return jnp.mean(loss)

@@ -62,20 +62,9 @@ def pipeline_forward(stage_fn, params_by_stage, x_micro, *, mesh,
             axis)
         return outs
 
-    other = tuple(a for a in mesh.axis_names if a != axis)
     in_specs = (jax.tree.map(lambda _: P(axis), params_by_stage),
                 P())
-    return _shard_map(spmd, mesh, in_specs, P())(params_by_stage, x_micro)
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: jax.shard_map(check_vma=...) on new
-    releases, jax.experimental.shard_map.shard_map(check_rep=...) on the
-    installed one (replica checking off in both — `outs` is deliberately
-    stage-varying until the final psum)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    # replica checking off: `outs` is deliberately stage-varying until
+    # the final psum
+    return jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(params_by_stage, x_micro)

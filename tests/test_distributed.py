@@ -48,8 +48,8 @@ from repro.launch import steps
 from repro.models.factory import build_model
 from repro.train.optimizer import adamw
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2,2), ("data","model"))
+mesh = jax.make_mesh((2,2), ("data","model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch in ("deepseek-7b", "phi3.5-moe-42b-a6.6b", "mamba2-130m"):
     cfg = smoke_config(arch).replace(head_pad_to=2)
     model = build_model(cfg)
@@ -83,8 +83,8 @@ def test_pipeline_parallel_exact():
     out = run_subprocess_jax("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.pipeline_parallel import pipeline_forward
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2,2), ("pod","data"))
+mesh = jax.make_mesh((2,2), ("pod","data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 W = jnp.asarray(rng.standard_normal((2, 16, 16)).astype(np.float32)*0.3)
 stage_fn = lambda w, h: jnp.tanh(h @ w)
@@ -104,9 +104,10 @@ import tempfile, jax, jax.numpy as jnp, numpy as np
 from repro.train import checkpoint as ck
 tree = {"wq": jnp.arange(128, dtype=jnp.bfloat16).reshape(16, 8),
         "scale": jnp.ones(5)}
-from repro.launch.mesh import make_mesh_compat
-mesh8 = make_mesh_compat((4, 2), ("data", "model"))
-mesh2 = make_mesh_compat((2,), ("model",))
+mesh8 = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh2 = jax.make_mesh((2,), ("model",),
+                      axis_types=(jax.sharding.AxisType.Auto,))
 d = tempfile.mkdtemp()
 ck.save(d, 1, tree, mesh=mesh8)
 like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)

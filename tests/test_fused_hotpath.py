@@ -132,6 +132,26 @@ def test_int8_quantize_roundtrip_error_bounded(seed):
                           np.asarray(dq["dense_b"]))
 
 
+def test_stage0_fits_reduced_grid_not_trusted_cnn():
+    """The kernel epilogue holds the reduced grid's largest stage-0 model
+    at base 224; a 224 px trusted CNN's banded weights do not fit, so
+    use_kernel=None leaves such a stage 0 on the unfused path."""
+    from repro.kernels.image_transform import stage0_fits
+
+    def stage0(arch, res):
+        cfg = TahomaCNNConfig(arch.n_conv_layers, arch.conv_nodes,
+                              arch.dense_nodes, input_hw=res,
+                              input_channels=3)
+        params = init_cnn(jax.random.PRNGKey(0), cfg)
+        return Stage0(params, Representation(res, "rgb"),
+                      quantize_cnn(params))
+
+    small = stage0(TahomaCNNConfig(2, 32, 32), 56)
+    assert stage0_fits(small, 224, [112, 56, 28])
+    assert stage0_fits(small, 224, [112, 56, 28], int8=True)
+    assert not stage0_fits(stage0(TahomaCNNConfig(3, 48, 64), 224), 224)
+
+
 def test_make_fused_ingest_kernel_flag_validation():
     s0 = _stage0(0, 8)
     casc_fns = [lambda x: jnp.zeros(x.shape[0])]
