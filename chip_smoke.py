@@ -64,30 +64,6 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileClock:
-    """Seconds JAX spends in backend compilation (or fetching a compiled
-    program from the persistent cache), and the cache hits among them."""
-
-    def __init__(self):
-        import jax
-        self.seconds, self.programs, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.programs += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def report(self) -> str:
-        return (f"{self.seconds:.1f} s over {self.programs} programs "
-                f"({self.cache_hits} persistent-cache hits)")
-
-
 def training_frames(specs, size: Size, seed: int):
     from repro.data.synthetic import make_corpus
 
@@ -367,10 +343,11 @@ def main() -> int:
     log(f"platform={dev.platform} device_kind={dev.device_kind} "
         f"devices={len(devices)}")
     log(f"compile cache: {cache_dir}")
-    clock = CompileClock()
+    from repro.tracing import COMPILES
+
     run(SHARDED if args.chips > 1 else Size(), chips=args.chips,
         seed=args.seed)
-    log(f"compile seconds: {clock.report()}")
+    log(f"compile seconds: {COMPILES.report()}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}), flush=True)

@@ -68,6 +68,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.executor import make_fused_ingest
 from repro.core.transforms import color_transform
 from repro.engine.scan import CompiledCascade, VirtualColumnStore
@@ -89,7 +90,7 @@ def frame_signature(frames: np.ndarray, res: int = 8) -> np.ndarray:
 @dataclass
 class IngestStats:
     frames: int = 0            # frames consumed
-    chunks: int = 0            # fused scoring dispatches issued
+    chunks: int = 0            # chunks scored on the device
     refs: int = 0              # distinct (reference) frames scored
     skipped: int = 0           # near-duplicate frames aliased, not scored
     decided_labels: int = 0    # exact stage-0 decisions recorded
@@ -292,7 +293,9 @@ class IngestPipeline:
     ``ingest(frames, ids)`` (global row ids; chunks split internally)
     or sweep a resident corpus with ``run(images)``. Stateful across
     calls: the skip detector chains through the previous call's last
-    frame, so a camera stream can be fed in any batch granularity."""
+    frame, so a camera stream can be fed in any batch granularity. Each
+    chunk is one ``ingest.chunk`` span over its steps' spans
+    (``repro/tracing.py``)."""
 
     def __init__(self, cascades: Sequence[CompiledCascade], n_rows: int,
                  *, chunk: int = 64, skip: bool = True,
@@ -344,34 +347,45 @@ class IngestPipeline:
         int8 = (self.int8 and c0.stage0 is not None
                 and c0.stage0.qparams is not None)
         use_kernel = self.use_kernel if c0.stage0 is not None else False
-        self._anchor_fn = make_fused_ingest(
+        anchor = make_fused_ingest(
             c0.model_fns[:1], [c0.thresholds[0]], c0.reps[:1], [],
             out_res, stage0=c0.stage0, use_kernel=use_kernel,
-            int8=int8, jit=self.jit, emit_scores=True)
+            int8=int8, jit=False, emit_scores=True)
+        # named programs, so a device trace tells the anchor and each
+        # head apart (jit_ingest_anchor, jit_ingest_head_<k>)
+        anchor.__name__ = anchor.__qualname__ = "ingest_anchor"
+        self._anchor_fn = jax.jit(anchor) if self.jit else anchor
         self._head_fns = []
-        for c in self.cascades[1:]:
+        for k, c in enumerate(self.cascades[1:], start=1):
             def head(level, _fn=c.model_fns[0], _rep=c.reps[0]):
                 return _fn(color_transform(level, _rep.color))
+            head.__name__ = head.__qualname__ = f"ingest_head_{k}"
             self._head_fns.append(jax.jit(head) if self.jit else head)
 
     def _score_refs(self, frames: np.ndarray) -> np.ndarray:
-        """Stage-0 scores (n_ref, n_concepts) for a batch of reference
-        frames, padded to the static chunk shape."""
+        """Stage-0 scores (chunk, n_concepts) of one chunk of frames,
+        already padded to the static chunk shape. Each program call is
+        an ``ingest.dispatch`` span and each wait on a head's result an
+        ``ingest.wait``: a call returns before the device is done. The
+        wait on the anchor is ``ingest.anchor_wait``: ``jnp.asarray``
+        returns before the chunk's copy to the device ends, and the
+        copy finishes there."""
         import jax.numpy as jnp
 
-        nv = len(frames)
         if self._anchor_fn is None:
             self._build(frames.shape[1])
-        if nv < self.chunk:
-            pad = np.repeat(frames[-1:], self.chunk - nv, axis=0)
-            frames = np.concatenate([frames, pad])
-        _, levels, s0 = self._anchor_fn(jnp.asarray(frames))
-        cols = [np.asarray(s0)[:nv]]
+        with tracing.span("ingest.transfer"):
+            x = jnp.asarray(frames)
+        with tracing.span("ingest.dispatch"):
+            _, levels, s0 = self._anchor_fn(x)
+        with tracing.span("ingest.anchor_wait"):
+            cols = [np.asarray(s0)]
         for c, fn in zip(self.cascades[1:], self._head_fns):
-            lvl = levels[c.reps[0].resolution]
-            cols.append(np.asarray(fn(lvl))[:nv])
+            with tracing.span("ingest.dispatch"):
+                out = fn(levels[c.reps[0].resolution])
+            with tracing.span("ingest.wait"):
+                cols.append(np.asarray(out))
         self.stats.chunks += 1
-        self.stats.stage0_scores += nv * len(self.cascades)
         return np.stack(cols, axis=1)
 
     # ----------------------------------------------------- streaming ----
@@ -381,63 +395,88 @@ class IngestPipeline:
         labels into the index."""
         frames = np.asarray(frames, np.float32)
         ids = np.asarray(ids, np.int64)
-        idx = self.index
         for lo in range(0, len(ids), self.chunk):
-            blk = frames[lo:lo + self.chunk]
-            bids = ids[lo:lo + self.chunk]
-            self.stats.frames += len(bids)
-            idx.indexed[bids] = True
-            sigs = frame_signature(blk, self.skip_res)
-            ref_rows: list[int] = []
-            for i, rid in enumerate(bids):
-                diff = (float(np.abs(sigs[i] - self._prev_sig).mean())
-                        if self._prev_sig is not None else None)
-                if diff is not None and self.skip_threshold is None:
-                    self._calib_diffs.append(diff)
-                    if len(self._calib_diffs) >= self.calib_frames:
-                        self.skip_threshold = self.calibrate_threshold(
-                            self._calib_diffs)
-                dup = (self.skip and diff is not None
-                       and self._prev_ref is not None
-                       and self.skip_threshold is not None
-                       and diff <= self.skip_threshold)
-                if dup:
-                    idx.alias[rid] = self._prev_ref
-                    self.stats.skipped += 1
-                else:
-                    idx.alias[rid] = rid
-                    self._prev_ref = int(rid)
-                    ref_rows.append(i)
-                self._prev_sig = sigs[i]
-            if not ref_rows:
-                continue
-            ref_rows = np.asarray(ref_rows, np.int64)
-            rids = bids[ref_rows]
-            scores = self._score_refs(blk[ref_rows])
-            self.stats.refs += len(rids)
-            margins = np.empty_like(scores)
-            for k, casc in enumerate(self.cascades):
-                s0 = scores[:, k]
-                idx.scores[casc.concept][rids] = s0
-                lab, decided, margin = self._grade(casc, s0)
-                if decided.any():
-                    idx.decided.record(casc.key, rids[decided],
-                                       lab[decided])
-                    self.stats.decided_labels += int(decided.sum())
-                margins[:, k] = margin
-            cand = margins > 0.0
-            if idx.top_k is not None and idx.top_k < len(self.cascades):
-                # Focus-style cap: keep only the top_k best margins
-                order = np.argsort(-margins, axis=1, kind="stable")
-                capped = np.zeros_like(cand)
-                np.put_along_axis(capped, order[:, : idx.top_k], True,
-                                  axis=1)
-                cand &= capped
-            for k, casc in enumerate(self.cascades):
-                # decided-1 frames are always candidates; decided-0 never
-                col = idx.decided.column(casc.key)[rids]
-                idx.candidates[casc.concept][rids] = \
-                    (cand[:, k] | (col == 1)) & (col != 0)
+            with tracing.span("ingest.chunk"):
+                self._ingest_chunk(frames[lo:lo + self.chunk],
+                                   ids[lo:lo + self.chunk])
+
+    def _ingest_chunk(self, blk: np.ndarray, bids: np.ndarray) -> None:
+        idx = self.index
+        self.stats.frames += len(bids)
+        idx.indexed[bids] = True
+        with tracing.span("ingest.detect"):
+            ref_rows = self._detect(blk, bids)
+        if not len(ref_rows):
+            return
+        rids = bids[ref_rows]
+        nv = len(rids)
+        pad = self.chunk - nv
+        with tracing.span("ingest.gather"):
+            # a short chunk repeats its last scored frame up to the
+            # static chunk shape
+            refs = blk[np.concatenate([ref_rows,
+                                       np.repeat(ref_rows[-1], pad)])]
+        scores = self._score_refs(refs)[:nv]
+        self.stats.refs += nv
+        self.stats.stage0_scores += nv * len(self.cascades)
+        with tracing.span("ingest.grade"):
+            self._record(rids, scores)
+
+    def _detect(self, blk: np.ndarray, bids: np.ndarray) -> np.ndarray:
+        """The skip detector over one chunk: aliases the near-duplicates
+        in the index and returns the rows of ``blk`` left to score."""
+        idx = self.index
+        sigs = frame_signature(blk, self.skip_res)
+        ref_rows: list[int] = []
+        for i, rid in enumerate(bids):
+            diff = (float(np.abs(sigs[i] - self._prev_sig).mean())
+                    if self._prev_sig is not None else None)
+            if diff is not None and self.skip_threshold is None:
+                self._calib_diffs.append(diff)
+                if len(self._calib_diffs) >= self.calib_frames:
+                    self.skip_threshold = self.calibrate_threshold(
+                        self._calib_diffs)
+            dup = (self.skip and diff is not None
+                   and self._prev_ref is not None
+                   and self.skip_threshold is not None
+                   and diff <= self.skip_threshold)
+            if dup:
+                idx.alias[rid] = self._prev_ref
+                self.stats.skipped += 1
+            else:
+                idx.alias[rid] = rid
+                self._prev_ref = int(rid)
+                ref_rows.append(i)
+            self._prev_sig = sigs[i]
+        return np.asarray(ref_rows, np.int64)
+
+    def _record(self, rids: np.ndarray, scores: np.ndarray) -> None:
+        """Grade the scored rows ``rids`` into the index: scores, exact
+        decided labels and candidate sets."""
+        idx = self.index
+        margins = np.empty_like(scores)
+        for k, casc in enumerate(self.cascades):
+            s0 = scores[:, k]
+            idx.scores[casc.concept][rids] = s0
+            lab, decided, margin = self._grade(casc, s0)
+            if decided.any():
+                idx.decided.record(casc.key, rids[decided],
+                                   lab[decided])
+                self.stats.decided_labels += int(decided.sum())
+            margins[:, k] = margin
+        cand = margins > 0.0
+        if idx.top_k is not None and idx.top_k < len(self.cascades):
+            # Focus-style cap: keep only the top_k best margins
+            order = np.argsort(-margins, axis=1, kind="stable")
+            capped = np.zeros_like(cand)
+            np.put_along_axis(capped, order[:, : idx.top_k], True,
+                              axis=1)
+            cand &= capped
+        for k, casc in enumerate(self.cascades):
+            # decided-1 frames are always candidates; decided-0 never
+            col = idx.decided.column(casc.key)[rids]
+            idx.candidates[casc.concept][rids] = \
+                (cand[:, k] | (col == 1)) & (col != 0)
 
     @staticmethod
     def calibrate_threshold(diffs, *, min_ratio: float = 4.0,

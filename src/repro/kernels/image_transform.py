@@ -444,6 +444,7 @@ def fused_pyramid_stage0(images, out_res, params, rep, *, qparams=None,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(block, resident)),
         interpret=resolve_interpret(interpret),
+        name="fused_pyramid_stage0",
     )(x, *operands)
     levels = {r: out[i][:b].reshape(b, r, r, 3)
               for i, r in enumerate(out_res)}

@@ -81,7 +81,8 @@ def _images(width, sharding):
 def test_fused_pyramid_stage0_compiles_for_v5e(one_chip, no_compile_cache,
                                                arch_name, int8, width):
     """The chunk-ingest kernel at base 224 with levels {112, 56, 28}, at
-    the service's smallest slab (16) and the scan chunk (256)."""
+    the service's smallest slab (16) and the scan chunk (256). The
+    compiled Mosaic op carries the kernel's name."""
     from repro.kernels.image_transform import fused_pyramid_stage0
 
     s0 = _stage0(arch_name, int8)
@@ -90,7 +91,10 @@ def test_fused_pyramid_stage0_compiles_for_v5e(one_chip, no_compile_cache,
         return fused_pyramid_stage0(imgs, LEVELS, s0.params, s0.rep,
                                     qparams=s0.qparams, interpret=False)
     compiled = jax.jit(run).lower(_images(width, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernel = [ln for ln in compiled.as_text().splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernel
+    assert all("%fused_pyramid_stage0" in ln for ln in kernel)
 
 
 @pytest.mark.parametrize("width,specs", [
