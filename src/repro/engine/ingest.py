@@ -76,15 +76,22 @@ from repro.engine.scan import CompiledCascade, VirtualColumnStore
 
 # ------------------------------------------------------ skip detector ----
 def frame_signature(frames: np.ndarray, res: int = 8) -> np.ndarray:
-    """Downsampled grayscale detector signature (B, res, res): channel
-    mean then box-mean pooling — pure host numpy, a few hundred bytes
-    per frame, the cheap difference feature NoScope's detectors use."""
+    """Downsampled grayscale detector signature (B, res, res): the box
+    mean, over a res x res grid, of the channel mean — pure host numpy,
+    a few hundred bytes per frame, the cheap difference feature
+    NoScope's detectors use. One float32 pass: each grid cell's k rows
+    are added as contiguous vectors, then each cell's k*c contiguous
+    values are summed, and the sum is divided once by k*k*c. (A channel
+    mean first would reduce a size-c innermost axis, which NumPy does
+    one short inner loop at a time, an order of magnitude slower.)"""
     frames = np.asarray(frames, np.float32)
-    b, hw = frames.shape[0], frames.shape[1]
+    b, hw, c = frames.shape[0], frames.shape[1], frames.shape[3]
     res = min(res, hw)
     k = hw // res
-    gray = frames[:, : res * k, : res * k].mean(axis=3)
-    return gray.reshape(b, res, k, res, k).mean(axis=(2, 4))
+    cells = frames[:, : res * k, : res * k].reshape(b * res, k,
+                                                     res * k * c)
+    sums = cells.sum(axis=1).reshape(b, res, res, k * c).sum(axis=3)
+    return sums / np.float32(k * k * c)
 
 
 @dataclass
@@ -427,10 +434,16 @@ class IngestPipeline:
         in the index and returns the rows of ``blk`` left to score."""
         idx = self.index
         sigs = frame_signature(blk, self.skip_res)
+        # consecutive-frame differences in one op; _prev_sig is the
+        # frame before this chunk, skipped or not
+        prev = sigs[:1] if self._prev_sig is None else self._prev_sig[None]
+        diffs = np.abs(sigs - np.concatenate([prev, sigs[:-1]])).reshape(
+            len(sigs), -1).mean(axis=1).tolist()
+        if self._prev_sig is None:
+            diffs[0] = None
+        self._prev_sig = sigs[-1]
         ref_rows: list[int] = []
-        for i, rid in enumerate(bids):
-            diff = (float(np.abs(sigs[i] - self._prev_sig).mean())
-                    if self._prev_sig is not None else None)
+        for i, (rid, diff) in enumerate(zip(bids.tolist(), diffs)):
             if diff is not None and self.skip_threshold is None:
                 self._calib_diffs.append(diff)
                 if len(self._calib_diffs) >= self.calib_frames:
@@ -445,9 +458,8 @@ class IngestPipeline:
                 self.stats.skipped += 1
             else:
                 idx.alias[rid] = rid
-                self._prev_ref = int(rid)
+                self._prev_ref = rid
                 ref_rows.append(i)
-            self._prev_sig = sigs[i]
         return np.asarray(ref_rows, np.int64)
 
     def _record(self, rids: np.ndarray, scores: np.ndarray) -> None:

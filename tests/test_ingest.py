@@ -129,6 +129,78 @@ def test_streaming_granularity_invariant(stream):
                               pipe.index.decided.column(k))
 
 
+def _reference_signature(frames, res):
+    """The detector signature as plainly written: channel mean, then the
+    box mean over a res x res grid of the cropped frame."""
+    frames = np.asarray(frames, np.float32)
+    b, hw = frames.shape[0], frames.shape[1]
+    res = min(res, hw)
+    k = hw // res
+    gray = frames[:, : res * k, : res * k].mean(axis=3)
+    return gray.reshape(b, res, k, res, k).mean(axis=(2, 4))
+
+
+@pytest.mark.parametrize("shape,res,view", [
+    ((64, 240, 240, 3), 8, None),         # the benchmark cell's chunk
+    ((4, 250, 250, 3), 8, None),          # hw % res != 0: cropped
+    ((4, 4, 4, 3), 8, None),              # res > hw
+    ((4, 32, 32, 1), 8, None),            # single channel
+    ((8, 67, 67, 4), 8, np.s_[::2, 1::2, 1::2, :3]),   # strided view
+    ((1, 96, 96, 3), 8, None),            # batch of one
+])
+def test_frame_signature_matches_reference(shape, res, view):
+    frames = np.random.default_rng(7).random(shape, dtype=np.float32)
+    if view is not None:
+        frames = frames[view]
+        assert not frames.flags.c_contiguous
+    sig = frame_signature(frames, res)
+    want = _reference_signature(frames, res)
+    assert sig.dtype == np.float32 and sig.shape == want.shape
+    np.testing.assert_allclose(sig, want, rtol=0, atol=1e-6)
+
+
+def _reference_aliases(frames, threshold, calib_frames):
+    """Sequential skip decisions from the reference signatures: a frame
+    whose diff to the previous frame is at most the threshold aliases
+    the last reference; with ``threshold=None`` nothing is skipped until
+    the first ``calib_frames`` diffs have set it."""
+    sigs = _reference_signature(frames, 8)
+    diffs = np.abs(sigs[1:] - sigs[:-1]).mean(axis=(1, 2))
+    live_from = 1
+    if threshold is None:
+        threshold = IngestPipeline.calibrate_threshold(diffs[:calib_frames])
+        live_from = calib_frames
+    alias = np.zeros(len(frames), np.int64)
+    ref = 0
+    for i in range(1, len(frames)):
+        if i >= live_from and diffs[i - 1] <= threshold:
+            alias[i] = ref
+        else:
+            alias[i] = ref = i
+    return alias
+
+
+@pytest.mark.parametrize("threshold", [0.008, None])
+def test_skip_decisions_match_reference_for_any_feed_size(stream,
+                                                          threshold):
+    """Feeding the stream 64, 7 or 33 frames a call gives the same
+    aliases, skips and references, equal to the reference's; with a
+    learned threshold, calibration ends inside a chunk."""
+    frames, _, _, cascades, _ = stream
+    ids = np.arange(len(frames))
+    want = _reference_aliases(frames, threshold, 48)
+    n_refs = int((want == ids).sum())
+    assert 0 < n_refs < len(frames)
+    for feed in (64, 7, 33):
+        pipe = IngestPipeline(cascades, len(frames), chunk=64, skip=True,
+                              skip_threshold=threshold, calib_frames=48)
+        for lo in range(0, len(frames), feed):
+            pipe.ingest(frames[lo:lo + feed], ids[lo:lo + feed])
+        assert np.array_equal(pipe.index.alias, want)
+        assert pipe.stats.refs == n_refs
+        assert pipe.stats.skipped == len(frames) - n_refs
+
+
 # --------------------------------------------------- differential oracle --
 def _cold_rows(frames, cascades):
     return ScanEngine(frames, chunk=32).execute(cascades).indices
