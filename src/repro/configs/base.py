@@ -19,12 +19,16 @@ class MoEConfig:
     d_ff_shared: int = 0          # per shared expert
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    # the chosen experts' normalised weights are scaled by this
+    # (DeepSeek-V3's sigmoid router, ffn.apply_held_moe)
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
-    q_lora_rank: int = 1536
+    """DeepSeek-V2 multi-head latent attention. ``q_lora_rank=None``: q is
+    projected from the hidden state directly (DeepSeek-V2-Lite, Kimi-VL)."""
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64

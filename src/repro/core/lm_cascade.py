@@ -34,6 +34,11 @@ class LMLevel:
     p_high: float | None = None
 
 
+def yes_share(pair):
+    """(..., 2) yes and no logits -> P(yes) (...): their two-way softmax."""
+    return jax.nn.softmax(pair.astype(jnp.float32), -1)[..., 0]
+
+
 def lm_predicate_score(level: LMLevel, tokens: np.ndarray) -> np.ndarray:
     """tokens (B, S) -> P(yes) (B,). Uses the last-position logits."""
     t = tokens
@@ -43,7 +48,7 @@ def lm_predicate_score(level: LMLevel, tokens: np.ndarray) -> np.ndarray:
         level.params, {"tokens": jnp.asarray(t)}, remat_policy="none",
         logits_last_only=True)
     pair = logits[:, -1, jnp.asarray([level.yes_token, level.no_token])]
-    return np.asarray(jax.nn.softmax(pair.astype(jnp.float32), -1)[:, 0])
+    return np.asarray(yes_share(pair))
 
 
 def calibrate(levels: Sequence[LMLevel], tokens, truth,

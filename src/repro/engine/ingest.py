@@ -102,6 +102,9 @@ class IngestStats:
     skipped: int = 0           # near-duplicate frames aliased, not scored
     decided_labels: int = 0    # exact stage-0 decisions recorded
     stage0_scores: int = 0     # stage-0 scores computed (refs x concepts)
+    vlm_tokens: int = 0        # VLM decoder tokens computed (padding too)
+    vlm_expert_rows: int = 0   # token-expert assignments the held experts
+    #                            computed, summed over MoE layers
 
 
 class CandidateIndex:
@@ -336,6 +339,7 @@ class IngestPipeline:
         self._prev_ref: int | None = None
         self._anchor_fn: Callable | None = None
         self._head_fns: list = []
+        self._vlm_heads: list = []
 
     # ------------------------------------------------- scoring rungs ----
     def _build(self, base_hw: int) -> None:
@@ -345,11 +349,26 @@ class IngestPipeline:
         pyramid + stage-0 one program, the Pallas pyramid+stage-0
         kernel on TPU — emitting the pooled levels the OTHER concepts'
         stage-0 heads read, so per scored chunk the pyramid is
-        materialized exactly once."""
+        materialized exactly once. Concepts whose first level is a
+        question to a VLM (models/kimi_vl.VLMQuestion) read the base
+        frames instead: one ``_VLMHead`` per model scores all of its
+        questions at once."""
         import jax
 
+        from repro.models.kimi_vl import VLMQuestion
+
         c0 = self.cascades[0]
-        head_res = [c.reps[0].resolution for c in self.cascades[1:]]
+        if isinstance(c0.model_fns[0], VLMQuestion):
+            raise ValueError("the anchor concept's first level must be a "
+                             "CNN; put VLM questions after it")
+        cnn, vlm = [], {}
+        for k, c in enumerate(self.cascades[1:], start=1):
+            fn = c.model_fns[0]
+            if isinstance(fn, VLMQuestion):
+                vlm.setdefault(id(fn.model), []).append((k, fn))
+            else:
+                cnn.append((k, c))
+        head_res = [c.reps[0].resolution for _, c in cnn]
         out_res = tuple(sorted(set(head_res), reverse=True))
         int8 = (self.int8 and c0.stage0 is not None
                 and c0.stage0.qparams is not None)
@@ -363,11 +382,13 @@ class IngestPipeline:
         anchor.__name__ = anchor.__qualname__ = "ingest_anchor"
         self._anchor_fn = jax.jit(anchor) if self.jit else anchor
         self._head_fns = []
-        for k, c in enumerate(self.cascades[1:], start=1):
+        for k, c in cnn:
             def head(level, _fn=c.model_fns[0], _rep=c.reps[0]):
                 return _fn(color_transform(level, _rep.color))
             head.__name__ = head.__qualname__ = f"ingest_head_{k}"
-            self._head_fns.append(jax.jit(head) if self.jit else head)
+            self._head_fns.append((k, c.reps[0].resolution,
+                                   jax.jit(head) if self.jit else head))
+        self._vlm_heads = [_VLMHead(m) for m in vlm.values()]
 
     def _score_refs(self, frames: np.ndarray) -> np.ndarray:
         """Stage-0 scores (chunk, n_concepts) of one chunk of frames,
@@ -386,14 +407,17 @@ class IngestPipeline:
         with tracing.span("ingest.dispatch"):
             _, levels, s0 = self._anchor_fn(x)
         with tracing.span("ingest.anchor_wait"):
-            cols = [np.asarray(s0)]
-        for c, fn in zip(self.cascades[1:], self._head_fns):
+            cols = {0: np.asarray(s0)}
+        for k, res, fn in self._head_fns:
             with tracing.span("ingest.dispatch"):
-                out = fn(levels[c.reps[0].resolution])
+                out = fn(levels[res])
             with tracing.span("ingest.wait"):
-                cols.append(np.asarray(out))
+                cols[k] = np.asarray(out)
+        for head in self._vlm_heads:
+            cols.update(head(x, self.stats))
         self.stats.chunks += 1
-        return np.stack(cols, axis=1)
+        return np.stack([cols[k] for k in range(len(self.cascades))],
+                        axis=1)
 
     # ----------------------------------------------------- streaming ----
     def ingest(self, frames: np.ndarray, ids: np.ndarray) -> None:
@@ -539,6 +563,35 @@ class IngestPipeline:
             ids = np.arange(len(images), dtype=np.int64)
         self.ingest(images, ids)
         return self.index
+
+
+class _VLMHead:
+    """The questions that share one VLM, scored as one head: the model's
+    tower once per chunk, then chunk x questions sequences through its
+    decoder, giving each question's column. Each program's dispatch and
+    the wait on its output is one span, ``ingest.vlm_tower`` and
+    ``ingest.vlm_decoder``."""
+
+    def __init__(self, members: list):
+        import jax.numpy as jnp
+
+        self.model = members[0][1].model
+        self.cols = [k for k, _ in members]
+        self.questions = jnp.asarray(np.stack([q.ids for _, q in members]),
+                                     jnp.int32)
+
+    def __call__(self, frames, stats: IngestStats) -> dict:
+        """{concept column: scores (B,)} of the frames on the device."""
+        with tracing.span("ingest.vlm_tower"):
+            tokens = self.model.tower(frames)
+            tokens.block_until_ready()
+        with tracing.span("ingest.vlm_decoder"):
+            scores, rows = self.model.scores(tokens, self.questions)
+            scores, rows = np.asarray(scores), np.asarray(rows)
+        stats.vlm_tokens += scores.size * (tokens.shape[1]
+                                           + self.questions.shape[1])
+        stats.vlm_expert_rows += int(rows.sum())
+        return dict(zip(self.cols, scores.T))
 
 
 # ------------------------------------------------------ orchestration ----

@@ -202,10 +202,14 @@ def init_mla(key, cfg):
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 8)
     dt = pdtype(cfg)
+    if m.q_lora_rank:
+        q = {"w_dq": dense_init(ks[0], (d, m.q_lora_rank), 0, dt),
+             "q_norm": jnp.ones((m.q_lora_rank,), dt),
+             "w_uq": dense_init(ks[1], (m.q_lora_rank, h * qk), 0, dt)}
+    else:   # no q-LoRA: q = h W_q
+        q = {"w_q": dense_init(ks[0], (d, h * qk), 0, dt)}
     return {
-        "w_dq": dense_init(ks[0], (d, m.q_lora_rank), 0, dt),
-        "q_norm": jnp.ones((m.q_lora_rank,), dt),
-        "w_uq": dense_init(ks[1], (m.q_lora_rank, h * qk), 0, dt),
+        **q,
         "w_dkv": dense_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim),
                             0, dt),
         "kv_norm": jnp.ones((m.kv_lora_rank,), dt),
@@ -217,13 +221,17 @@ def init_mla(key, cfg):
 
 
 def mla_q(p, x, cfg, cos, sin):
-    """-> q_nope (B,S,H,nope), q_rope (B,S,H,rope)."""
+    """-> q_nope (B,S,H,nope), q_rope (B,S,H,rope); q through the q-LoRA
+    (w_dq, q_norm, w_uq) or, without one, straight from w_q."""
     m, h = cfg.mla, cfg.n_heads
     b, s, _ = x.shape
-    cq = rmsnorm_vec(jnp.einsum("bsd,dr->bsr", x, p["w_dq"]), p["q_norm"],
-                     cfg.norm_eps)
-    q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"]).reshape(
-        b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if "w_q" in p:     # no q-LoRA
+        q = jnp.einsum("bsd,dh->bsh", x, p["w_q"])
+    else:
+        cq = rmsnorm_vec(jnp.einsum("bsd,dr->bsr", x, p["w_dq"]),
+                         p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"])
+    q = q.reshape(b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = apply_rope(q[..., m.qk_nope_head_dim:], cos, sin)
     return q_nope, q_rope

@@ -21,6 +21,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.common import activation, dense_init, pdtype
 from repro.sharding import policy
@@ -146,3 +147,86 @@ def apply_moe(p, x, cfg, n_groups: int):
     prob_frac = jnp.mean(probs, axis=(0, 1))
     aux = moe.num_experts * jnp.sum(token_frac * prob_frac) / moe.top_k
     return out.reshape(b, s, d), aux
+
+
+# ------------------------------------------------- held experts (EP) ---
+def init_held_moe(key, cfg, held):
+    """Router over all ``cfg.moe.num_experts`` experts (and its noaux_tc
+    correction bias), the weights of the experts in ``held`` only, and
+    the shared experts: one expert-parallel rank's share of a layer."""
+    moe = cfg.moe
+    ks = jax.random.split(key, 5)
+    dt = pdtype(cfg)
+    d, f, n = cfg.d_model, moe.d_ff_expert, len(held)
+
+    def stacked(k, shape):
+        return jnp.stack([dense_init(kk, shape, 0, dt)
+                          for kk in jax.random.split(k, n)])
+
+    p = {"w_router": dense_init(ks[0], (d, moe.num_experts), 0, jnp.float32),
+         "router_bias": jnp.zeros((moe.num_experts,), jnp.float32),
+         "w_gate_e": stacked(ks[1], (d, f)),
+         "w_up_e": stacked(ks[2], (d, f)),
+         "w_down_e": stacked(ks[3], (f, d))}
+    if moe.num_shared_experts:
+        p["shared"] = init_mlp(
+            ks[4], cfg, d_ff=moe.num_shared_experts * moe.d_ff_shared,
+            gated=True)
+    return p
+
+
+def router_scores(p, x):
+    """x (T, d) -> (sigmoid scores (T, E), scores + correction bias
+    (T, E), by which the experts are chosen)."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p["w_router"])
+    scores = jax.nn.sigmoid(logits)
+    return scores, scores + p["router_bias"]
+
+
+def route(p, x, cfg):
+    """x (T, d) -> (experts (T, k), weights (T, k)) over all experts, as
+    DeepSeek-V3's sigmoid router (noaux_tc) routes: the top k of score +
+    correction bias, weighted by their scores normalised to 1 and scaled
+    by ``routed_scaling_factor``."""
+    moe = cfg.moe
+    scores, biased = router_scores(p, x)
+    _, experts = jax.lax.top_k(biased, moe.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=1)
+    weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return experts, weights * moe.routed_scaling_factor
+
+
+def apply_held_moe(p, x, cfg, held):
+    """x (..., d) -> (out (..., d), rows (len(held),) int32): the held
+    experts' part of an MoE layer plus the shared experts, with no
+    capacity: every token-expert assignment that lands on a held expert
+    is computed. Assignments are sorted by held expert (the rest last)
+    and run as one grouped product per weight (``lax.ragged_dot``), then
+    summed back per token; ``rows`` counts each held expert's
+    assignments."""
+    moe = cfg.moe
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    n = len(held)
+    experts, weights = route(p, x, cfg)
+    local = np.full((moe.num_experts,), n, np.int32)
+    local[list(held)] = np.arange(n)
+    slot = jnp.asarray(local)[experts].reshape(-1)      # (T*k,)
+    order = jnp.argsort(slot, stable=True)
+    rows = jnp.sum(slot[:, None] == jnp.arange(n)[None], 0, jnp.int32)
+    tok = order // moe.top_k
+    xs = x[tok]
+    act = activation(cfg.act)
+    h = act(jax.lax.ragged_dot(xs, p["w_gate_e"], rows)) \
+        * jax.lax.ragged_dot(xs, p["w_up_e"], rows)
+    y = jax.lax.ragged_dot(h, p["w_down_e"], rows)
+    w = weights.reshape(-1)[order]
+    # rows past the held groups belong to no group: masked, not weighted
+    y = jnp.where((slot[order] < n)[:, None], y * w[:, None], 0.0)
+    # back to (token, choice) order by the inverse permutation, a gather
+    # (a scatter-add here is refused by the TPU compiler's fusion pass)
+    out = y[jnp.argsort(order)].reshape(-1, moe.top_k, shape[-1]).sum(1)
+    out = out.astype(x.dtype)
+    if "shared" in p:
+        out = out + apply_mlp(p["shared"], x, cfg)
+    return out.reshape(shape), rows
