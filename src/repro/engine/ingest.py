@@ -102,7 +102,10 @@ class IngestStats:
     skipped: int = 0           # near-duplicate frames aliased, not scored
     decided_labels: int = 0    # exact stage-0 decisions recorded
     stage0_scores: int = 0     # stage-0 scores computed (refs x concepts)
-    vlm_tokens: int = 0        # VLM decoder tokens computed (padding too)
+    vlm_tokens: int = 0        # VLM positions answered: frames x questions
+    #                            x (image + question tokens), padding too
+    vlm_decoder_tokens: int = 0  # VLM positions the decoder computed: one
+    #                            shared image prefix a frame (padding too)
     vlm_expert_rows: int = 0   # token-expert assignments the held experts
     #                            computed, summed over MoE layers
 
@@ -567,10 +570,10 @@ class IngestPipeline:
 
 class _VLMHead:
     """The questions that share one VLM, scored as one head: the model's
-    tower once per chunk, then chunk x questions sequences through its
-    decoder, giving each question's column. Each program's dispatch and
-    the wait on its output is one span, ``ingest.vlm_tower`` and
-    ``ingest.vlm_decoder``."""
+    tower once per chunk, then one packed row a frame (its image tokens,
+    then every question) through its decoder, giving each question's
+    column. Each program's dispatch and the wait on its output is one
+    span, ``ingest.vlm_tower`` and ``ingest.vlm_decoder``."""
 
     def __init__(self, members: list):
         import jax.numpy as jnp
@@ -588,8 +591,9 @@ class _VLMHead:
         with tracing.span("ingest.vlm_decoder"):
             scores, rows = self.model.scores(tokens, self.questions)
             scores, rows = np.asarray(scores), np.asarray(rows)
-        stats.vlm_tokens += scores.size * (tokens.shape[1]
-                                           + self.questions.shape[1])
+        (b, n_img), (k, q_len) = tokens.shape[:2], self.questions.shape
+        stats.vlm_tokens += b * k * (n_img + q_len)
+        stats.vlm_decoder_tokens += b * (n_img + k * q_len)
         stats.vlm_expert_rows += int(rows.sum())
         return dict(zip(self.cols, scores.T))
 

@@ -116,9 +116,11 @@ def repeat_kv(k, gp: int):
 
 
 def sdpa(q, k, v, *, causal: bool, q_positions=None, k_positions=None,
-         k_valid=None, gp: int = 1):
+         k_valid=None, visible=None, gp: int = 1):
     """GQA-grouped scaled-dot-product attention.
     q (B,S,H,dh); k/v (B,T,KH,dh) with H = KH*gp -> out (B,S,H,dh).
+    ``visible`` (S,T) bool, if given, hides from query s each key t it
+    holds False for, on top of ``causal`` and ``k_valid``.
 
     KV heads are NEVER materialized repeated: q is regrouped to
     (B,S,KH,gp,dh) and contracted against k/v directly. Besides avoiding
@@ -146,6 +148,8 @@ def sdpa(q, k, v, *, causal: bool, q_positions=None, k_positions=None,
             scores = jnp.where(mask[:, :, None], neg, scores)
     if k_valid is not None:  # (B,T) bool: cache entries that exist
         scores = jnp.where(k_valid[:, None, None, None, :], scores, neg)
+    if visible is not None:
+        scores = jnp.where(visible, scores, neg)
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bkgst,btkd->bskgd", probs.astype(q.dtype), v)
     return ctx.reshape(b, s, h, v.shape[-1])  # dv != dh under MLA
@@ -248,9 +252,11 @@ def mla_latent_kv(p, x, cfg, cos, sin):
     return c_kv, k_rope
 
 
-def mla_attention_full(p, x, cfg, cos, sin, *, causal=True, chunk=0):
+def mla_attention_full(p, x, cfg, cos, sin, *, causal=True, chunk=0,
+                       visible=None):
     """Train/prefill path: reconstruct per-head K,V from the latent then run
-    standard attention (flops-faithful to the naive formulation)."""
+    standard attention (flops-faithful to the naive formulation).
+    ``visible`` (S,S) bool: as ``sdpa``'s, on the unchunked path only."""
     m, h = cfg.mla, cfg.n_heads
     b, s, _ = x.shape
     q_nope, q_rope = mla_q(p, x, cfg, cos, sin)
@@ -264,9 +270,11 @@ def mla_attention_full(p, x, cfg, cos, sin, *, causal=True, chunk=0):
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (b, s, h, m.qk_rope_head_dim))], -1)
     if chunk and s > chunk:
+        if visible is not None:
+            raise ValueError("chunked attention takes no visibility mask")
         ctx = chunked_sdpa(q, k, v, causal=causal, chunk=chunk)
     else:
-        ctx = sdpa(q, k, v, causal=causal)
+        ctx = sdpa(q, k, v, causal=causal, visible=visible)
     out = jnp.einsum("bsh,hd->bsd", ctx.reshape(b, s, h * m.v_head_dim),
                      p["wo"])
     return out, (c_kv, k_rope)

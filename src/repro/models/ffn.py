@@ -196,8 +196,9 @@ def route(p, x, cfg):
     return experts, weights * moe.routed_scaling_factor
 
 
-def apply_held_moe(p, x, cfg, held):
-    """x (..., d) -> (out (..., d), rows (len(held),) int32): the held
+def apply_held_moe(p, x, experts, weights, cfg, held):
+    """x (..., d), each token's experts and weights (T, k) as ``route``
+    gives them -> (out (..., d), rows (len(held),) int32): the held
     experts' part of an MoE layer plus the shared experts, with no
     capacity: every token-expert assignment that lands on a held expert
     is computed. Assignments are sorted by held expert (the rest last)
@@ -208,7 +209,6 @@ def apply_held_moe(p, x, cfg, held):
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     n = len(held)
-    experts, weights = route(p, x, cfg)
     local = np.full((moe.num_experts,), n, np.int32)
     local[list(held)] = np.arange(n)
     slot = jnp.asarray(local)[experts].reshape(-1)      # (T*k,)

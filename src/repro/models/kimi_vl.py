@@ -3,14 +3,23 @@
 
 A frame is centre-cropped to the tower's square and mapped from [0, 1)
 to [-1, 1] as SigLIP does, and MoonViT (models/vision) turns it into
-image tokens. Each question is a row of token ids; the decoder reads
-the frame's image tokens followed by the question's ids, one sequence
-per (frame, question), and the score is the two-way softmax of the yes
-and no logits at the last position (``core/lm_cascade.yes_share``, as
-``lm_predicate_score`` scores). Only those two rows of the output
+image tokens. Each question is a row of token ids; each answer is the
+decoder's reading of the frame's image tokens followed by the
+question's ids, and the score is the two-way softmax of the yes and no
+logits at the question's last position (``core/lm_cascade.yes_share``,
+as ``lm_predicate_score`` scores). Only those two rows of the output
 head are computed.
 
-The decoder: causal MLA (models/attention, no q-LoRA) with 1D RoPE,
+A frame's K questions share its image tokens as one prefix: the decoder
+runs one packed row a frame, the image tokens then the K questions one
+after another, each question a branch that sees the prefix and itself
+(``tree_layout``). The decoder is causal, so the prefix's states do not
+depend on what follows it, and each answer equals that of the frame's
+and question's own sequence while the prefix is computed once, not K
+times.
+
+The decoder: MLA (models/attention, no q-LoRA) under that tree-causal
+mask, with 1D RoPE,
 ``first_k_dense`` dense SwiGLU layers, then MoE layers of which this
 device holds the experts ``held`` (models/ffn.apply_held_moe: routing
 over all experts, no token dropped), a final RMSNorm. Everything runs
@@ -61,13 +70,47 @@ def init_decoder(key, cfg, held):
                                       jnp.float32) * t.d_model ** -0.5}
 
 
-def _layer(lp, h, t, rope, held):
+def tree_layout(n_img: int, k: int, q_len: int):
+    """One packed row of ``n_img`` image tokens then ``k`` question
+    branches of ``q_len`` -> (positions (S,), visible (S, S) bool, seq
+    (k, n_img + q_len): each (frame, question) sequence's tokens as
+    indices into the row). Each branch restarts at position ``n_img``,
+    so every token keeps the RoPE angle it has in its own sequence; a
+    key is visible to a query iff it lies at or before the query's
+    position and is an image token or in the query's own branch."""
+    branch = np.repeat(np.arange(-1, k), [n_img] + [q_len] * k)
+    pos = np.concatenate([np.arange(n_img),
+                          np.tile(np.arange(n_img, n_img + q_len), k)])
+    visible = (pos[None] <= pos[:, None]) & (
+        (branch[None] < 0) | (branch[None] == branch[:, None]))
+    seq = np.stack([np.r_[:n_img, n_img + j * q_len:n_img + (j + 1) * q_len]
+                    for j in range(k)])
+    return pos, visible, seq
+
+
+def _moe(p, x, t, held, seq):
+    """Packed rows x (B, S, d) -> ``apply_held_moe``'s (out, rows), each
+    packed token run once. The router reads the tokens in the order of
+    the (frame, question) sequences, ``x[:, seq]``, the prefix once in
+    each, as the reference routes them (``bench/vlm_route_gap.py`` lines
+    the two up token by token); each packed token takes its routing
+    from the first sequence that holds it."""
+    b, s, d = x.shape
+    routed = ffn.route(p, x[:, seq.reshape(-1)].reshape(-1, d), t)
+    first = np.unique(seq, return_index=True)[1]          # (S,)
+    packed = [a.reshape(b, seq.size, -1)[:, first].reshape(b * s, -1)
+              for a in routed]
+    return ffn.apply_held_moe(p, x, *packed, t, held)
+
+
+def _layer(lp, h, t, rope, visible, seq, held):
     x = apply_norm(lp["ln1"], h, t, "rmsnorm")
-    out, _ = attn.mla_attention_full(lp["attn"], x, t, *rope, causal=True)
+    out, _ = attn.mla_attention_full(lp["attn"], x, t, *rope, causal=False,
+                                     visible=visible)
     h = h + out
     x = apply_norm(lp["ln2"], h, t, "rmsnorm")
     if "moe" in lp:
-        out, rows = ffn.apply_held_moe(lp["moe"], x, t, held)
+        out, rows = _moe(lp["moe"], x, t, held, seq)
         return h + out, rows
     return h + ffn.apply_mlp(lp["mlp"], x, t), None
 
@@ -75,27 +118,29 @@ def _layer(lp, h, t, rope, held):
 def decoder_scores(params, image_tokens, questions, cfg, held, yes: int,
                    no: int):
     """image_tokens (B, T, d), questions (K, L) int -> (P(yes) (B, K),
-    held experts' assignments per MoE layer (n_moe, len(held)) int32)."""
+    held experts' assignments per MoE layer (n_moe, len(held)) int32),
+    over one packed row of T + K L tokens a frame (``tree_layout``)."""
     t = cfg.text
     b, n_img, d = image_tokens.shape
     k, q_len = questions.shape
-    q_emb = jnp.take(params["embed"], questions, axis=0)
+    pos, visible, seq = tree_layout(n_img, k, q_len)
+    q_emb = jnp.take(params["embed"], questions.reshape(-1), axis=0)
     h = jnp.concatenate(
-        [jnp.broadcast_to(image_tokens[:, None], (b, k, n_img, d)),
-         jnp.broadcast_to(q_emb[None], (b, k, q_len, d))], axis=2)
-    h = h.reshape(b * k, n_img + q_len, d)
-    pos = jnp.broadcast_to(jnp.arange(n_img + q_len), h.shape[:2])
-    rope = rope_for_heads(pos, t.mla.qk_rope_head_dim, t.rope_theta)
+        [image_tokens, jnp.broadcast_to(q_emb[None], (b, k * q_len, d))],
+        axis=1)
+    rope = rope_for_heads(jnp.asarray(pos)[None], t.mla.qk_rope_head_dim,
+                          t.rope_theta)
     for lp in params["dense"]:
-        h, _ = _layer(lp, h, t, rope, held)
+        h, _ = _layer(lp, h, t, rope, visible, seq, held)
 
     def step(h, lp):
-        return _layer(lp, h, t, rope, held)
+        return _layer(lp, h, t, rope, visible, seq, held)
 
     h, rows = jax.lax.scan(step, h, params["moe"])
-    last = apply_norm(params["final_norm"], h[:, -1], t, "rmsnorm")
+    last = apply_norm(params["final_norm"], h[:, n_img + q_len - 1::q_len],
+                      t, "rmsnorm")                     # (B, K, d)
     logits = last @ params["head"][:, jnp.asarray([yes, no])]
-    return yes_share(logits).reshape(b, k), rows
+    return yes_share(logits), rows
 
 
 def frames_to_images(frames, image_hw: int):

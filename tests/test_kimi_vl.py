@@ -22,7 +22,7 @@ from repro.configs import kimi_vl_a3b  # noqa: E402
 from repro.models import attention as attn  # noqa: E402
 from repro.models import ffn  # noqa: E402
 from repro.models.kimi_vl import (KimiVL, VLMQuestion, init_kimi_vl,  # noqa: E402
-                                  vlm_decoder, vlm_tower)
+                                  tree_layout, vlm_decoder, vlm_tower)
 from repro.models.vision import bicubic_matrix  # noqa: E402
 
 HW = 20            # stored frames; the tower reads the centred 16 px
@@ -147,17 +147,70 @@ def test_mla_with_q_lora_keeps_its_layout():
     assert {"w_dq", "q_norm", "w_uq"} <= set(p) and "w_q" not in p
 
 
-def test_decoder_scores_match_reference(params):
-    cfg, m = program_cfg(), model_of()
+def _decode(decoder_params, img, questions):
+    cfg = program_cfg()
+    return jax.jit(lambda p, i, q: vlm_decoder(
+        p, i, q, cfg=cfg, held=HELD, yes=5, no=11))(decoder_params, img,
+                                                   questions)
+
+
+@pytest.mark.parametrize("k", [3, 1])
+def test_decoder_scores_match_reference(params, k):
+    """K questions a frame share its image prefix in one packed row; each
+    answer matches the reference's own (frame, question) sequence. With
+    K = 1 the row is that sequence."""
+    m = model_of()
     img = ref.tower(params["tower"], frames(4, 1), m, "highest")
-    got, rows = jax.jit(lambda p, i, q: vlm_decoder(
-        p, i, q, cfg=cfg, held=HELD, yes=5, no=11))(params["decoder"], img,
-                                                   QUESTIONS)
-    want, tie = ref.decoder(params["decoder"], img, QUESTIONS, m, "highest")
-    assert got.shape == (4, 3) and rows.shape == (2, 2)
+    got, rows = _decode(params["decoder"], img, QUESTIONS[:k])
+    want, tie = ref.decoder(params["decoder"], img, QUESTIONS[:k], m,
+                            "highest")
+    assert got.shape == (4, k) and rows.shape == (2, 2)
     assert np.all(np.asarray(tie) > 1e-4)     # no routing near a tie
     np.testing.assert_allclose(got, want, rtol=TOL, atol=TOL)
     assert np.all((np.asarray(got) > 0) & (np.asarray(got) < 1))
+
+
+def test_tree_layout_keeps_each_tokens_position():
+    pos, visible, seq = tree_layout(4, 3, 5)
+    assert pos.tolist() == [0, 1, 2, 3] + [4, 5, 6, 7, 8] * 3
+    # each sequence's tokens hold the positions 0..8 in order
+    assert np.array_equal(pos[seq], np.tile(np.arange(9), (3, 1)))
+    _, causal, one = tree_layout(4, 1, 5)
+    assert np.array_equal(causal, np.tril(np.ones((9, 9), bool)))
+    assert one.tolist() == [list(range(9))]
+    # a branch's last query sees the prefix and its own tokens: 4 + 5
+    assert visible.sum(1)[[8, 13, 18]].tolist() == [9, 9, 9]
+
+
+def test_questions_do_not_see_each_other(params):
+    """A new question in one branch leaves every other column as it was:
+    the mask keeps the branches apart."""
+    img = ref.tower(params["tower"], frames(4, 1), model_of(), "highest")
+    base, _ = _decode(params["decoder"], img, QUESTIONS)
+    other = QUESTIONS.at[1].set((QUESTIONS[1] + 17) % 97)
+    moved, _ = _decode(params["decoder"], img, other)
+    np.testing.assert_allclose(moved[:, [0, 2]], base[:, [0, 2]],
+                               rtol=1e-6, atol=1e-6)
+    assert np.max(np.abs(moved[:, 1] - base[:, 1])) > 1e-4
+
+
+def test_rows_count_the_packed_tokens(params):
+    """A router bias that sends every token to both held experts: each
+    held expert computes each packed token once a layer, B (T + K L)
+    tokens, not the B K (T + L) of one sequence a question."""
+    dec = params["decoder"]
+    bias = dec["moe"]["moe"]["router_bias"]
+    skew = dict(dec, moe=dict(dec["moe"], moe=dict(
+        dec["moe"]["moe"], router_bias=bias.at[:, jnp.asarray(HELD)]
+        .add(10.0))))
+    img = ref.tower(params["tower"], frames(4, 1), model_of(), "highest")
+    _, rows = _decode(skew, img, QUESTIONS)
+    assert np.all(np.asarray(rows) == 4 * (4 + 3 * 5))
+
+
+def _held_moe(p, x, t, held):
+    routed = ffn.route(p, x.reshape(-1, x.shape[-1]), t)
+    return ffn.apply_held_moe(p, x, *routed, t, held)
 
 
 def _moe_layer(params):
@@ -183,7 +236,7 @@ def test_expert_parallel_shares_add_up_to_the_uncut_layer():
         share = dict(lp, **{k: lp[k][np.asarray(held)] for k in
                             ("w_gate_e", "w_up_e", "w_down_e")})
         with jax.default_matmul_precision("highest"):
-            out, rows = ffn.apply_held_moe(share, x, t, held)
+            out, rows = _held_moe(share, x, t, held)
         total = total + (out - shared)
     np.testing.assert_allclose(total, want, rtol=TOL, atol=TOL)
 
@@ -197,7 +250,7 @@ def test_dropless_under_a_skewed_router(params):
     skew = dict(lp, router_bias=lp["router_bias"].at[jnp.asarray(HELD)]
                 .add(10.0))
     with jax.default_matmul_precision("highest"):
-        out, rows = ffn.apply_held_moe(skew, x, t, HELD)
+        out, rows = _held_moe(skew, x, t, HELD)
     assert int(rows.sum()) == x.shape[0] * x.shape[1] * len(HELD)
     assert np.all(np.asarray(rows) == x.shape[0] * x.shape[1])
     want, _ = ref._moe(skew, x, model_of(),
@@ -238,6 +291,8 @@ def test_ingest_pipeline_vlm_head_matches_reference(params):
                                    rtol=TOL, atol=TOL)
     # 2 chunks of 16 (the second padded) x 3 questions x (4 + 5) tokens
     assert pipe.stats.vlm_tokens == 2 * 16 * 3 * 9
+    # the decoder computes one row a frame: 4 image + 3 x 5 question tokens
+    assert pipe.stats.vlm_decoder_tokens == 2 * 16 * (4 + 3 * 5)
     assert pipe.stats.vlm_expert_rows > 0
     # a VLM question is a model_fn of its own too
     one = VLMQuestion(_cascades(params, True)[2].model_fns[0].model,
@@ -260,6 +315,7 @@ def test_cnn_columns_unchanged_by_a_vlm_head(params):
                               both.index.candidates[c])
     assert np.array_equal(alone.index.alias, both.index.alias)
     assert alone.stats.vlm_tokens == 0 and alone.stats.vlm_expert_rows == 0
+    assert alone.stats.vlm_decoder_tokens == 0
 
 
 def test_vlm_anchor_is_refused(params):

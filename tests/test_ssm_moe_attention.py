@@ -171,6 +171,35 @@ def test_head_padding_exact():
                                atol=1e-5, rtol=1e-5)
 
 
+def _plain_attention(q, k, v, causal):
+    """Per-head softmax(q k^T / sqrt(dh)) v in numpy, GQA by repeat."""
+    gp = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, gp, axis=2), np.repeat(v, gp, axis=2)
+    s = np.einsum("bshd,bthd->bhst", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("bhst,bthd->bshd", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_sdpa_without_a_mask_is_plain_attention(causal):
+    """``visible=None`` leaves ``sdpa`` the attention it was, causal or
+    not; an explicit mask of the same keys gives the same result."""
+    rng = np.random.default_rng(4)
+    q = rng.standard_normal((2, 6, 4, 8)).astype(np.float32)
+    k = rng.standard_normal((2, 6, 2, 8)).astype(np.float32)
+    v = rng.standard_normal((2, 6, 2, 8)).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        got = attn.sdpa(q, k, v, causal=causal, gp=2)
+        shown = np.tril(np.ones((6, 6), bool)) if causal else \
+            np.ones((6, 6), bool)
+        masked = attn.sdpa(q, k, v, causal=False, visible=shown, gp=2)
+    want = _plain_attention(q, k, v, causal)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(masked, got)
+
+
 def test_head_layout_assignments():
     """The production (pad_to=16) layouts for every assigned arch."""
     cases = {(40, 8): (48, 8, 6), (24, 8): (32, 8, 4), (6, 6): (16, 16, 1),
